@@ -95,6 +95,9 @@ def main() -> None:
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     t_all = time.monotonic()
     failures = []
     for name, desc in MODULES:

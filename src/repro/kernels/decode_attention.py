@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from . import _compat
 
 NEG_INF = -1.0e30
 
@@ -110,7 +109,7 @@ def decode_attention_pallas(q: jnp.ndarray, k_cache: jnp.ndarray,
         scratch_shapes=[pltpu.VMEM((g, 1), jnp.float32),
                         pltpu.VMEM((g, 1), jnp.float32),
                         pltpu.VMEM((g, d), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lens, qt, kt, vt)

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from . import _compat
 
 NEG_INF = -1.0e30
 
@@ -136,7 +135,7 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         scratch_shapes=[pltpu.VMEM((bq_, 1), jnp.float32),
                         pltpu.VMEM((bq_, 1), jnp.float32),
                         pltpu.VMEM((bq_, d), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

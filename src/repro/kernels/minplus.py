@@ -28,7 +28,10 @@ below keep the working set VMEM-resident instead:
 
 Hardware note (DESIGN.md §3): (min, +) has no MXU mapping — these are VPU
 kernels; tiles are (8k, 128)-aligned.  Off-TPU all kernels default to
-interpret mode (``interpret=None`` auto-selects from the JAX backend).
+interpret mode (``interpret=None`` auto-selects from the JAX backend) so
+the CPU tests can check their results; interpret mode cannot show what
+Mosaic refuses, which ``tests/test_tpu_compile.py`` checks by compiling
+for a described v5e.
 """
 from __future__ import annotations
 
@@ -40,15 +43,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from . import _compat
-
 INF_CUT = 1.0e8
 _COUNT_CLIP = 1.0e30
 
 
 def _default_interpret() -> bool:
-    """Interpret off-TPU, compile on TPU — callers no longer thread the
-    flag; pass an explicit bool to override."""
+    """Interpret off-TPU (the CPU tests), compile on TPU; pass an explicit
+    bool to override.  Runs meant for the chip check the platform first
+    (``chip_smoke.py``) instead of relying on this."""
     return jax.default_backend() != "tpu"
 
 
@@ -57,35 +59,119 @@ def _resolve_interpret(interpret) -> bool:
 
 
 # ---------------------------------------------------------------------------
+# Shared relaxation and in-kernel row/column reads.
+#
+# Mosaic lowers a dynamic row read or write of a VMEM ref on the sublane
+# axis (``ref[0, pl.ds(k, 1), :]``), but refuses a dynamic single-lane
+# column slice and ``lax.dynamic_slice`` on values altogether.  A column
+# is therefore read as a masked lane reduction that selects one element
+# per row — exact, since min(x, +inf, ..., +inf) == x — and written as a
+# masked whole-tile store.
+# ---------------------------------------------------------------------------
+
+def _fw_step(Td, Tn, a_d, a_n, b_d, b_n, mask):
+    """One rank-1 pivot update on a tile — the exact ref.fw_counts_ref
+    expressions (operand order preserved for bitwise equality).  ``mask``
+    is the ``notk`` mask restricted to the tile (or None when the tile
+    provably excludes row/col k)."""
+    cand = a_d + b_d
+    ncand = jnp.minimum(a_n * b_n, _COUNT_CLIP)
+    lt = cand < Td
+    eq = (cand == Td) & (cand < INF_CUT)
+    if mask is not None:
+        lt = lt & mask
+        eq = eq & mask
+    Td = jnp.where(lt, cand, Td)
+    Tn = jnp.where(lt, ncand, Tn + jnp.where(eq, ncand, 0.0))
+    Tn = jnp.minimum(Tn, _COUNT_CLIP)
+    return Td, Tn
+
+
+def _init_counts(W, eye):
+    """N0: 1 for finite off-diagonal edges, identity diagonal (== ref)."""
+    return jnp.where((W < INF_CUT) & ~eye, 1.0, 0.0) + eye.astype(W.dtype)
+
+
+def _iota(shape, axis, offset=0):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis) + offset
+
+
+def _col(X, k, col):
+    """Column k of X as [rows, 1]; ``col`` is X's lane-index iota."""
+    return jnp.min(jnp.where(col == k, X, jnp.inf), axis=1, keepdims=True)
+
+
+def _row(ref, k):
+    """Row k of a [1, rows, cols] block ref as [1, cols]."""
+    return ref[0, pl.ds(k, 1), :]
+
+
+def _set_row(ref, k, v):
+    ref[0, pl.ds(k, 1), :] = v
+
+
+def _set_col(ref, k, v, col):
+    ref[0] = jnp.where(col == k, v, ref[0])
+
+
+# ---------------------------------------------------------------------------
 # Batched VMEM-resident Floyd-Warshall with path counts.
 # ---------------------------------------------------------------------------
 
-def _fw_counts_kernel(w_ref, d_ref, n_ref, *, V: int):
-    W = w_ref[0]                                   # (V, V) fp32 in VMEM
-    row = jax.lax.broadcasted_iota(jnp.int32, (V, V), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (V, V), 1)
-    eye = (row == col)
-    N0 = jnp.where((W < INF_CUT) & ~eye, 1.0, 0.0) + eye.astype(W.dtype)
+_STRIP_ELEMS = 16 * 1024        # ~16 f32 vregs per strip operand
 
-    def body(k, carry):
-        D, N = carry
-        dik = jax.lax.dynamic_slice(D, (0, k), (V, 1))     # column k
-        dkj = jax.lax.dynamic_slice(D, (k, 0), (1, V))     # row k
-        nik = jax.lax.dynamic_slice(N, (0, k), (V, 1))
-        nkj = jax.lax.dynamic_slice(N, (k, 0), (1, V))
-        cand = dik + dkj
-        ncand = jnp.minimum(nik * nkj, _COUNT_CLIP)
-        notk = (row != k) & (col != k)
-        lt = (cand < D) & notk
-        eq = (cand == D) & notk & (cand < INF_CUT)
-        D = jnp.where(lt, cand, D)
-        N = jnp.where(lt, ncand, N + jnp.where(eq, ncand, 0.0))
-        N = jnp.minimum(N, _COUNT_CLIP)
-        return D, N
 
-    D, N = jax.lax.fori_loop(0, V, body, (W, N0))
-    d_ref[0] = D
-    n_ref[0] = N
+def _strip_rows(V: int) -> int:
+    """Row-strip height of the VMEM kernel: a power of two in [8, V], so
+    it divides the 128-multiple V, keeping one strip near 16 vregs."""
+    rs = 8
+    while rs * 2 <= V and rs * 2 * V <= _STRIP_ELEMS:
+        rs *= 2
+    return rs
+
+
+def _fw_counts_kernel(w_ref, d_ref, n_ref, *, V: int, rs: int):
+    """D and N live in the VMEM output blocks for all V pivots; each pivot
+    sweeps them in (rs, V) row strips.  Row k and column k are masked
+    from pivot k's update, so every strip reads their time-k values
+    whatever the sweep order."""
+    col = _iota((rs, V), 1)
+
+    def strips(fn):
+        def body(s, carry):
+            r0 = pl.multiple_of(s * rs, rs)
+            fn(pl.ds(r0, rs), _iota((rs, V), 0, r0))
+            return carry
+        jax.lax.fori_loop(0, V // rs, body, 0)
+
+    def init(rows, row):
+        W = w_ref[0, rows, :]
+        d_ref[0, rows, :] = W
+        n_ref[0, rows, :] = _init_counts(W, row == col)
+
+    strips(init)
+
+    def pivot(k, carry):
+        b_d, b_n = _row(d_ref, k), _row(n_ref, k)
+
+        def relax(rows, row):
+            Td, Tn = d_ref[0, rows, :], n_ref[0, rows, :]
+            Td, Tn = _fw_step(Td, Tn, _col(Td, k, col), _col(Tn, k, col),
+                              b_d, b_n, (row != k) & (col != k))
+            d_ref[0, rows, :] = Td
+            n_ref[0, rows, :] = Tn
+
+        strips(relax)
+        return carry
+
+    jax.lax.fori_loop(0, V, pivot, 0)
+
+
+def _vmem_limit(V: int) -> int:
+    """Scoped-VMEM request of the VMEM kernel: W, D and N blocks, each
+    double-buffered by the grid pipeline, plus 4 MiB for strip temporaries
+    and Mosaic's internal scratch."""
+    return 6 * V * V * 4 + (4 << 20)
 
 
 def _pad_isolated(W: jnp.ndarray, Vp: int) -> jnp.ndarray:
@@ -115,18 +201,19 @@ def fw_counts_pallas(W: jnp.ndarray, *, interpret: bool | None = None
     B, V0, _ = W.shape
     Vp = max(128, -(-V0 // 128) * 128)
     W = _pad_isolated(W, Vp)
-    kern = functools.partial(_fw_counts_kernel, V=Vp)
+    kern = functools.partial(_fw_counts_kernel, V=Vp, rs=_strip_rows(Vp))
+    block = pl.BlockSpec((1, Vp, Vp), lambda b: (b, 0, 0))
     D, N = pl.pallas_call(
         kern,
         grid=(B,),
-        in_specs=[pl.BlockSpec((1, Vp, Vp), lambda b: (b, 0, 0))],
-        out_specs=[pl.BlockSpec((1, Vp, Vp), lambda b: (b, 0, 0)),
-                   pl.BlockSpec((1, Vp, Vp), lambda b: (b, 0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((B, Vp, Vp), W.dtype),
-                   jax.ShapeDtypeStruct((B, Vp, Vp), W.dtype)],
-        compiler_params=_compat.CompilerParams(
-            dimension_semantics=("parallel",)),
+        in_specs=[block],
+        out_specs=[block, block],
+        out_shape=[jax.ShapeDtypeStruct((B, Vp, Vp), W.dtype)] * 2,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_vmem_limit(Vp)),
         interpret=interpret,
+        name="fw_counts_vmem",
     )(W)
     D, N = D[:, :V0, :V0], N[:, :V0, :V0]
     if squeeze:
@@ -162,61 +249,34 @@ def fw_counts_pallas(W: jnp.ndarray, *, interpret: bool | None = None
 #
 # D and N live in HBM between the per-pivot-block pallas_calls (a host
 # Python loop unrolled at trace time); each grid program touches only
-# (bt, bt) tiles, so VMEM stays O(bt^2) regardless of V.
+# (bt, bt) tiles, so VMEM stays O(bt^2) regardless of V.  Inside a
+# program the tile being relaxed lives in its output block, so rows of it
+# are read with ``_row`` like the snapshots.
 # ---------------------------------------------------------------------------
-
-def _fw_init_counts(W: jnp.ndarray) -> jnp.ndarray:
-    """N0: 1 for finite off-diagonal edges, identity diagonal (== ref)."""
-    V = W.shape[-1]
-    eye = jnp.eye(V, dtype=bool)
-    return jnp.where((W < INF_CUT) & ~eye, 1.0, 0.0) + eye.astype(W.dtype)
-
-
-def _fw_step(Td, Tn, a_d, a_n, b_d, b_n, mask):
-    """One rank-1 pivot update on a tile — the exact ref.fw_counts_ref
-    expressions (operand order preserved for bitwise equality).  ``mask``
-    is the ``notk`` mask restricted to the tile (or None when the tile
-    provably excludes row/col k)."""
-    cand = a_d + b_d
-    ncand = jnp.minimum(a_n * b_n, _COUNT_CLIP)
-    lt = cand < Td
-    eq = (cand == Td) & (cand < INF_CUT)
-    if mask is not None:
-        lt = lt & mask
-        eq = eq & mask
-    Td = jnp.where(lt, cand, Td)
-    Tn = jnp.where(lt, ncand, Tn + jnp.where(eq, ncand, 0.0))
-    Tn = jnp.minimum(Tn, _COUNT_CLIP)
-    return Td, Tn
-
 
 def _fw_diag_kernel(d_ref, n_ref, do_ref, no_ref, rd_ref, rn_ref,
                     cd_ref, cn_ref, *, bt: int):
     """Phase 1: relax the (bt, bt) pivot block over its own bt pivots,
     emitting per-pivot row snapshots (rd/rn, row k at time k) and column
     snapshots (cd/cn, column k at time k)."""
-    row = jax.lax.broadcasted_iota(jnp.int32, (bt, bt), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (bt, bt), 1)
+    row, col = _iota((bt, bt), 0), _iota((bt, bt), 1)
+    do_ref[0], no_ref[0] = d_ref[0], n_ref[0]
+    cd_ref[0] = jnp.zeros((bt, bt), cd_ref.dtype)
+    cn_ref[0] = jnp.zeros((bt, bt), cn_ref.dtype)
 
     def body(k, carry):
-        D, N, RD, RN, CD, CN = carry
-        b_d = jax.lax.dynamic_slice(D, (k, 0), (1, bt))   # row k @ time k
-        b_n = jax.lax.dynamic_slice(N, (k, 0), (1, bt))
-        a_d = jax.lax.dynamic_slice(D, (0, k), (bt, 1))   # col k @ time k
-        a_n = jax.lax.dynamic_slice(N, (0, k), (bt, 1))
-        RD = jax.lax.dynamic_update_slice(RD, b_d, (k, 0))
-        RN = jax.lax.dynamic_update_slice(RN, b_n, (k, 0))
-        CD = jax.lax.dynamic_update_slice(CD, a_d, (0, k))
-        CN = jax.lax.dynamic_update_slice(CN, a_n, (0, k))
+        D, N = do_ref[0], no_ref[0]
+        b_d, b_n = _row(do_ref, k), _row(no_ref, k)    # row k @ time k
+        a_d, a_n = _col(D, k, col), _col(N, k, col)    # col k @ time k
+        _set_row(rd_ref, k, b_d)
+        _set_row(rn_ref, k, b_n)
+        _set_col(cd_ref, k, a_d, col)
+        _set_col(cn_ref, k, a_n, col)
         D, N = _fw_step(D, N, a_d, a_n, b_d, b_n, (row != k) & (col != k))
-        return D, N, RD, RN, CD, CN
+        do_ref[0], no_ref[0] = D, N
+        return carry
 
-    z = jnp.zeros((bt, bt), d_ref.dtype)
-    D, N, RD, RN, CD, CN = jax.lax.fori_loop(
-        0, bt, body, (d_ref[0], n_ref[0], z, z, z, z))
-    do_ref[0], no_ref[0] = D, N
-    rd_ref[0], rn_ref[0] = RD, RN
-    cd_ref[0], cn_ref[0] = CD, CN
+    jax.lax.fori_loop(0, bt, body, 0)
 
 
 def _fw_panel_kernel(d_ref, n_ref, sd_ref, sn_ref, dd_ref, dn_ref,
@@ -241,36 +301,30 @@ def _fw_panel_kernel(d_ref, n_ref, sd_ref, sn_ref, dd_ref, dn_ref,
 
     @pl.when(j != kk)
     def _relax():
-        SD, SN = sd_ref[0], sn_ref[0]
-        iot = jax.lax.broadcasted_iota(jnp.int32, (bt, bt), 0 if is_row
-                                       else 1)
+        col = _iota((bt, bt), 1)
+        iot = _iota((bt, bt), 0) if is_row else col
+        od_ref[0], on_ref[0] = d_ref[0], n_ref[0]
+        if not is_row:
+            pd_ref[0] = jnp.zeros((bt, bt), pd_ref.dtype)
+            pn_ref[0] = jnp.zeros((bt, bt), pn_ref.dtype)
 
         def body(k, carry):
-            D, N, PD, PN = carry
+            D, N = od_ref[0], on_ref[0]
             if is_row:
-                own_d = jax.lax.dynamic_slice(D, (k, 0), (1, bt))
-                own_n = jax.lax.dynamic_slice(N, (k, 0), (1, bt))
-                PD = jax.lax.dynamic_update_slice(PD, own_d, (k, 0))
-                PN = jax.lax.dynamic_update_slice(PN, own_n, (k, 0))
-                a_d = jax.lax.dynamic_slice(SD, (0, k), (bt, 1))
-                a_n = jax.lax.dynamic_slice(SN, (0, k), (bt, 1))
-                b_d, b_n = own_d, own_n
+                b_d, b_n = _row(od_ref, k), _row(on_ref, k)
+                _set_row(pd_ref, k, b_d)
+                _set_row(pn_ref, k, b_n)
+                a_d, a_n = _col(sd_ref[0], k, col), _col(sn_ref[0], k, col)
             else:
-                own_d = jax.lax.dynamic_slice(D, (0, k), (bt, 1))
-                own_n = jax.lax.dynamic_slice(N, (0, k), (bt, 1))
-                PD = jax.lax.dynamic_update_slice(PD, own_d, (0, k))
-                PN = jax.lax.dynamic_update_slice(PN, own_n, (0, k))
-                b_d = jax.lax.dynamic_slice(SD, (k, 0), (1, bt))
-                b_n = jax.lax.dynamic_slice(SN, (k, 0), (1, bt))
-                a_d, a_n = own_d, own_n
+                a_d, a_n = _col(D, k, col), _col(N, k, col)
+                _set_col(pd_ref, k, a_d, col)
+                _set_col(pn_ref, k, a_n, col)
+                b_d, b_n = _row(sd_ref, k), _row(sn_ref, k)
             D, N = _fw_step(D, N, a_d, a_n, b_d, b_n, iot != k)
-            return D, N, PD, PN
+            od_ref[0], on_ref[0] = D, N
+            return carry
 
-        z = jnp.zeros((bt, bt), d_ref.dtype)
-        D, N, PD, PN = jax.lax.fori_loop(
-            0, bt, body, (d_ref[0], n_ref[0], z, z))
-        od_ref[0], on_ref[0] = D, N
-        pd_ref[0], pn_ref[0] = PD, PN
+        jax.lax.fori_loop(0, bt, body, 0)
 
 
 def _fw_outer_kernel(d_ref, n_ref, cd_ref, cn_ref, rd_ref, rn_ref,
@@ -287,15 +341,12 @@ def _fw_outer_kernel(d_ref, n_ref, cd_ref, cn_ref, rd_ref, rn_ref,
 
     @pl.when((i != kk) & (j != kk))
     def _relax():
-        CD, CN = cd_ref[0], cn_ref[0]
-        RD, RN = rd_ref[0], rn_ref[0]
+        col = _iota((bt, bt), 1)
 
         def body(k, carry):
             D, N = carry
-            a_d = jax.lax.dynamic_slice(CD, (0, k), (bt, 1))
-            a_n = jax.lax.dynamic_slice(CN, (0, k), (bt, 1))
-            b_d = jax.lax.dynamic_slice(RD, (k, 0), (1, bt))
-            b_n = jax.lax.dynamic_slice(RN, (k, 0), (1, bt))
+            a_d, a_n = _col(cd_ref[0], k, col), _col(cn_ref[0], k, col)
+            b_d, b_n = _row(rd_ref, k), _row(rn_ref, k)
             return _fw_step(D, N, a_d, a_n, b_d, b_n, None)
 
         D, N = jax.lax.fori_loop(0, bt, body, (d_ref[0], n_ref[0]))
@@ -309,8 +360,8 @@ def fw_counts_tiled_pallas(W: jnp.ndarray, *, bt: int = 128,
 
     W: [B, V, V] (or [V, V]) float32 with 0 diagonal.  V is padded to a
     multiple of ``bt`` with isolated nodes.  Per-grid-program working set
-    is O(bt^2) — use this when 3 x V^2 x 4B exceeds VMEM (see
-    ``ops.FW_TILED_AUTO_V`` for the dispatch knee).
+    is O(bt^2) — use this when the VMEM-resident kernel's blocks exceed
+    VMEM (see ``ops.FW_TILED_AUTO_V`` for the dispatch knee).
     """
     interpret = _resolve_interpret(interpret)
     squeeze = W.ndim == 2
@@ -320,10 +371,14 @@ def fw_counts_tiled_pallas(W: jnp.ndarray, *, bt: int = 128,
     Vt = max(bt, -(-V0 // bt) * bt)
     nb = Vt // bt
     W = _pad_isolated(W, Vt)
-    D, N = W, _fw_init_counts(W)
+    D, N = W, _init_counts(W, jnp.eye(Vt, dtype=bool))
 
     spec = pl.BlockSpec((1, bt, bt), lambda b: (b, 0, 0))
     shp = jax.ShapeDtypeStruct((B, bt, bt), W.dtype)
+
+    def params(n_grid):
+        return pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * n_grid)
 
     for kk in range(nb):
         k0 = kk * bt
@@ -336,9 +391,9 @@ def fw_counts_tiled_pallas(W: jnp.ndarray, *, bt: int = 128,
             in_specs=[spec, spec],
             out_specs=[spec] * 6,
             out_shape=[shp] * 6,
-            compiler_params=_compat.CompilerParams(
-                dimension_semantics=("parallel",)),
+            compiler_params=params(1),
             interpret=interpret,
+            name="fw_tiled_diag",
         )(dD, dN)
 
         # -- phase 2: row + col panels, emitting panel snapshots -----------
@@ -355,9 +410,9 @@ def fw_counts_tiled_pallas(W: jnp.ndarray, *, bt: int = 128,
             in_specs=[tile_j, tile_j] + [fixed] * 6,
             out_specs=[tile_j] * 4,
             out_shape=[row_shp] * 4,
-            compiler_params=_compat.CompilerParams(
-                dimension_semantics=("parallel", "parallel")),
+            compiler_params=params(2),
             interpret=interpret,
+            name="fw_tiled_row_panel",
         )(rowD, rowN, cdD, cdN, dD2, dN2, rdD, rdN)
         colD = jax.lax.dynamic_slice(D, (0, 0, k0), (B, Vt, bt))
         colN = jax.lax.dynamic_slice(N, (0, 0, k0), (B, Vt, bt))
@@ -367,9 +422,9 @@ def fw_counts_tiled_pallas(W: jnp.ndarray, *, bt: int = 128,
             in_specs=[tile_i, tile_i] + [fixed] * 6,
             out_specs=[tile_i] * 4,
             out_shape=[col_shp] * 4,
-            compiler_params=_compat.CompilerParams(
-                dimension_semantics=("parallel", "parallel")),
+            compiler_params=params(2),
             interpret=interpret,
+            name="fw_tiled_col_panel",
         )(colD, colN, rdD, rdN, dD2, dN2, cdD, cdN)
         D = jax.lax.dynamic_update_slice(D, rowD2, (0, k0, 0))
         N = jax.lax.dynamic_update_slice(N, rowN2, (0, k0, 0))
@@ -386,9 +441,9 @@ def fw_counts_tiled_pallas(W: jnp.ndarray, *, bt: int = 128,
             in_specs=[full, full, cpan, cpan, rpan, rpan],
             out_specs=[full, full],
             out_shape=[jax.ShapeDtypeStruct((B, Vt, Vt), W.dtype)] * 2,
-            compiler_params=_compat.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "parallel")),
+            compiler_params=params(3),
             interpret=interpret,
+            name="fw_tiled_outer",
         )(D, N, csD, csN, rsD, rsN)
 
     D, N = D[:, :V0, :V0], N[:, :V0, :V0]
@@ -438,7 +493,7 @@ def minplus_tiled_pallas(A: jnp.ndarray, B: jnp.ndarray, *,
                   pl.BlockSpec((bk, bn), lambda i, j, k: (k, j))],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), A.dtype),
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(Ap, Bp)

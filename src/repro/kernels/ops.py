@@ -4,7 +4,7 @@ CPU / pure-jnp reference, uniformly switchable via ``impl``.
 impl semantics:
   'auto'   — Pallas compiled on TPU; pure-jnp reference elsewhere (interpret
              mode is a correctness tool, far too slow for production CPU use).
-  'pallas' — force the Pallas kernel (interpret=True off-TPU). Tests use this.
+  'pallas' — force the Pallas kernel (interpret=True off-TPU, for tests).
   'ref'    — force the pure-jnp oracle.
 """
 from __future__ import annotations
@@ -108,11 +108,15 @@ def fw_impl_pallas(W):
 
 fw_impl_ref = ref.fw_counts_ref
 
-# Above this padded V the VMEM-resident FW's working set (~3 x Vp^2 x 4B
-# for W, D, N) no longer fits a 16 MB TPU VMEM budget: 768 -> ~6.8 MB
-# fits, the next 128-multiple (896 -> ~9.2 MB plus scratch) is already
-# marginal and 1024 -> ~12.6 MB fails in practice.  The blocked-tile FW
-# keeps O(bt^2) per grid program regardless of V.
+# Dispatch knee of ``fw_impl_tiled``.  The VMEM-resident kernel holds its
+# W, D and N blocks double-buffered, 6 x Vp^2 x 4 B.  Compiled for a v5e
+# without a chip: under the default 16 MiB scoped-VMEM limit it compiles
+# up to padded V 768 (13.5 MiB) and is refused from 896 on; with the
+# ``vmem_limit_bytes`` it now requests (``minplus._vmem_limit``) it also
+# compiles at 896-2048.  Which kernel is faster above 768 has not been
+# measured on the chip, so the knee stays where the default limit put
+# it.  The blocked-tile FW keeps O(bt^2) per grid program regardless of V
+# and compiles at padded V 1536 (homog256).
 FW_TILED_AUTO_V = 768
 
 

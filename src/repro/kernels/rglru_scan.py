@@ -15,7 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from . import _compat
 
 
 def _rglru_kernel(a_ref, x_ref, h0_ref, y_ref, hf_ref, h_scr, *, S: int):
@@ -68,7 +67,7 @@ def rglru_scan_pallas(x: jnp.ndarray, a: jnp.ndarray,
         out_shape=[jax.ShapeDtypeStruct((B, Dp, S), x.dtype),
                    jax.ShapeDtypeStruct((B, Dp), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((1, bd_), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(at, xt, h0)

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from . import _compat
 
 
 def _sscan_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, h0_ref,
@@ -89,7 +88,7 @@ def selective_scan_pallas(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
         out_shape=[jax.ShapeDtypeStruct((Bt, Dp, S), x.dtype),
                    jax.ShapeDtypeStruct((Bt, Dp, N), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((bd_, N), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(xt, dtt, A, jnp.asarray(B), jnp.asarray(C),

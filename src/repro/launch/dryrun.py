@@ -36,7 +36,7 @@ from ..models.model import build_model, init_cache, init_params
 from ..sharding import rules
 from ..sharding.partition import MeshInfo, use_sharding
 from ..train.optimizer import OptConfig, adamw_init
-from .mesh import make_production_mesh
+from .mesh import make_auto_mesh, make_production_mesh
 
 ARTIFACT_DIR = os.path.join("artifacts", "dryrun")
 
@@ -356,7 +356,7 @@ def _mesh_for(mesh_kind: str):
         dims = tuple(int(x) for x in tm.split("x"))
         axes = (("pod", "data", "model") if len(dims) == 3
                 else ("data", "model"))
-        return jax.make_mesh(dims, axes)
+        return make_auto_mesh(dims, axes)
     return make_production_mesh(multi_pod=(mesh_kind == "multi"))
 
 

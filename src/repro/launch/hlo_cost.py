@@ -45,16 +45,9 @@ COLLECTIVE_OPS = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
 
 
 def xla_cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalized across jax versions.
-
-    Depending on the jax version it returns a flat dict, a one-element list
-    of dicts (one per executable), or None; callers always want the flat
-    per-module dict.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
-    return dict(ca) if ca else {}
+    """``compiled.cost_analysis()`` as a flat dict ({} when XLA gives
+    none)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def shape_elems_bytes(type_str: str) -> tuple[int, int]:

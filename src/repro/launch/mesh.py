@@ -13,10 +13,18 @@ import jax
 from ..sharding.partition import MeshInfo
 
 
+def make_auto_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: the sharding rules place
+    activations with ``with_sharding_constraint``, which only accepts
+    Auto mesh axes (``jax.make_mesh`` defaults to Explicit)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_auto_mesh(shape, axes)
 
 
 def make_mesh_info(mesh) -> MeshInfo:
@@ -28,4 +36,4 @@ def make_host_mesh(n_model: int = 1):
     """Tiny mesh over whatever devices exist (CPU tests / examples)."""
     n = len(jax.devices())
     assert n % n_model == 0
-    return jax.make_mesh((n // n_model, n_model), ("data", "model"))
+    return make_auto_mesh((n // n_model, n_model), ("data", "model"))

@@ -20,7 +20,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -55,10 +54,12 @@ def shard_scorer(scorer, mesh: Mesh | None = None):
     mesh = mesh or population_mesh()
     n = n_pop_devices(mesh)
 
-    sharded = shard_map(
+    # Jitted, so each padded batch shape compiles once (an eager
+    # shard_map would trace and compile again on every call).
+    sharded = jax.jit(jax.shard_map(
         lambda b, no, w: scorer(b, no, w), mesh=mesh,
         in_specs=(P("pop"), P("pop"), P("pop")), out_specs=P("pop"),
-        check_rep=False)
+        check_vma=False))
 
     def call(batch, norms, weights):
         rows = int(np.asarray(batch["W"]).shape[0])

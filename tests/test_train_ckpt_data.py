@@ -105,7 +105,6 @@ def test_state_int8_converges_and_shrinks():
 
 
 def test_compressed_psum_matches_psum():
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     from repro.train.optimizer import compressed_psum
 
@@ -115,8 +114,8 @@ def test_compressed_psum_matches_psum():
     def f(x):
         return compressed_psum(x, "d")
 
-    y = jax.jit(shard_map(f, mesh=mesh, in_specs=P(None),
-                          out_specs=P(None)))(x)
+    y = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(None),
+                              out_specs=P(None)))(x)
     np.testing.assert_allclose(np.array(y), np.array(x), atol=1e-2)
 
 
