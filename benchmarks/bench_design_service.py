@@ -14,8 +14,7 @@ Two sections (PR 6):
   multi-device host, the scaling path).
 
 Results go to stdout as BENCH lines and to
-``artifacts/bench/design_service.json``; ``benchmarks.run`` merges that
-into ``BENCH_design_service.json`` at the repo root.
+``artifacts/bench/design_service.json``.
 """
 from __future__ import annotations
 

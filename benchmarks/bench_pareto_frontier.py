@@ -16,8 +16,7 @@ Two sections (PR 5):
   resulting front size/hypervolume.
 
 Results go to stdout as BENCH lines and to
-``artifacts/bench/pareto_frontier.json``; ``benchmarks.run`` merges that
-into ``BENCH_pareto_frontier.json`` at the repo root.
+``artifacts/bench/pareto_frontier.json``.
 """
 from __future__ import annotations
 

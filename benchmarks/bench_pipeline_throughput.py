@@ -48,9 +48,7 @@ the tier-value vector (TSV / backbone latency multipliers) as a runtime
 jit operand.  Target: >= 3x device over host.
 
 Results go to stdout as BENCH lines and to
-``artifacts/bench/pipeline_throughput.json``; ``benchmarks.run`` copies
-that to ``BENCH_pipeline_throughput.json`` at the repo root so the perf
-trajectory is versioned.
+``artifacts/bench/pipeline_throughput.json``.
 """
 from __future__ import annotations
 

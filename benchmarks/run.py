@@ -3,21 +3,15 @@
   PYTHONPATH=src python -m benchmarks.run [--full] [--only NAME]
 
 Emits ``BENCH,name,value,derived`` CSV lines and JSON artifacts under
-artifacts/bench/; each module's artifact is additionally *merged* into
-``BENCH_<name>.json`` at the repo root so the perf trajectory is versioned
-alongside the code (artifacts/ is transient).  Merging is section-wise
-(recursive on dict values): a run that only exercises a subset of a
-module's sections — quick mode skips expensive ones — updates those keys
-and preserves the rest, instead of churning the whole versioned file.
-Quick mode targets CI budgets; --full approaches the paper's budgets.
+artifacts/bench/ (not versioned).  These are rehearsals on whatever
+backend JAX finds; the benchmark measured on the chip is ``bench/run.py``
+(``BENCHMARK.json``).  Quick mode targets CI budgets; --full approaches
+the paper's budgets.
 """
 from __future__ import annotations
 
 import argparse
-import glob
-import json
 import os
-import shutil
 import time
 import traceback
 
@@ -39,57 +33,6 @@ MODULES = [
 ]
 
 
-ARTIFACT_DIR = os.path.join("artifacts", "bench")
-
-
-def _snapshot() -> dict[str, float]:
-    return {p: os.path.getmtime(p)
-            for p in glob.glob(os.path.join(ARTIFACT_DIR, "*.json"))}
-
-
-def _merge(old, new):
-    """Section-wise merge: new keys win, dict values merge recursively,
-    keys only present in ``old`` survive (partial runs must not drop the
-    sections they skipped)."""
-    out = dict(old)
-    for k, v in new.items():
-        if isinstance(v, dict) and isinstance(out.get(k), dict):
-            out[k] = _merge(out[k], v)
-        else:
-            out[k] = v
-    return out
-
-
-def promote_artifacts(before: dict[str, float]) -> list[str]:
-    """Merge artifacts written/updated since ``before`` into the repo-root
-    ``BENCH_<stem>.json`` (the versioned perf trajectory).  Non-dict or
-    unreadable JSON falls back to a plain copy."""
-    promoted = []
-    for p in glob.glob(os.path.join(ARTIFACT_DIR, "*.json")):
-        if p in before and os.path.getmtime(p) <= before[p]:
-            continue
-        stem = os.path.splitext(os.path.basename(p))[0]
-        dst = f"BENCH_{stem}.json"
-        merged = None
-        if os.path.exists(dst):
-            try:
-                with open(p) as f:
-                    new = json.load(f)
-                with open(dst) as f:
-                    old = json.load(f)
-                if isinstance(new, dict) and isinstance(old, dict):
-                    merged = _merge(old, new)
-            except (json.JSONDecodeError, OSError):
-                merged = None
-        if merged is not None:
-            with open(dst, "w") as f:
-                json.dump(merged, f, indent=1)
-        else:
-            shutil.copyfile(p, dst)
-        promoted.append(dst)
-    return promoted
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
@@ -106,14 +49,10 @@ def main() -> None:
         mod = __import__(f"benchmarks.bench_{name}", fromlist=["main"])
         print(f"\n=== bench_{name}: {desc} ===", flush=True)
         t0 = time.monotonic()
-        before = _snapshot()
         try:
             mod.main(quick=not args.full)
-            promoted = promote_artifacts(before)
             print(f"=== bench_{name} done in "
-                  f"{time.monotonic() - t0:.1f}s"
-                  + (f"; promoted {', '.join(promoted)}" if promoted else "")
-                  + " ===", flush=True)
+                  f"{time.monotonic() - t0:.1f}s ===", flush=True)
         except Exception as e:  # noqa: BLE001 — keep the suite running
             failures.append(name)
             print(f"=== bench_{name} FAILED: {type(e).__name__}: {e} ===")

@@ -54,6 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import spans
 from .cache import LRUCache
 from .cost import CostNormalizers
 from .objective import (NORM_DIM, TRACE_TERMS, Objective, compile_schedule,
@@ -61,6 +62,7 @@ from .objective import (NORM_DIM, TRACE_TERMS, Objective, compile_schedule,
 from .placement_hetero import HeteroRep
 from .placement_homog import HomogRep
 from .proxies import make_ranker, make_scorer
+from .spans import span
 from .topology import (HeteroGraphBatch, HomogGraphBatch, ScoreGraph,
                        stack_graphs)
 
@@ -315,12 +317,17 @@ class Evaluator:
         :func:`repro.sharding.population.shard_scorer` — while keeping
         the evaluator's dispatch accounting."""
         self.n_score_calls += 1
-        batch = self._with_demand(batch)
-        out = (fn or self.scorer)(
-            batch,
-            self._norm_vec if norms is None else norms,
-            self._weights_vec if weights is None else weights)
-        return {k: np.asarray(v) for k, v in out.items()}
+        with span(spans.SCORE):
+            with span(spans.SCORE_DISPATCH):
+                batch = self._with_demand(batch)
+                out = (fn or self.scorer)(
+                    batch,
+                    self._norm_vec if norms is None else norms,
+                    self._weights_vec if weights is None else weights)
+            with span(spans.SCORE_WAIT):
+                jax.block_until_ready(out)
+            with span(spans.SCORE_FETCH):
+                return {k: np.asarray(v) for k, v in out.items()}
 
     def archive_add(self, sols, costs, valid=None) -> None:
         """Fold scored host solutions into the population archive (no-op
@@ -940,41 +947,44 @@ class DevicePipeline:
 
         Returns ``(t, r, metrics, costs)`` for the filled slots.
         """
-        t, r, batch = make(self._key(rng), np.arange(n))
+        with span(spans.PRODUCE):
+            t, r, batch = make(self._key(rng), np.arange(n))
         costs, metrics = yield _tag(batch, weights)
-        costs = np.array(costs)
-        metrics = {k: np.array(v) for k, v in metrics.items()}
-        self.ev.n_generated += n
-        conn = metrics["connected"].astype(bool)
-        if self.ev.archive is not None:
-            self.ev.archive.add(costs, t, r, valid=conn)
-        for _ in range(max_rounds):
-            bad = np.nonzero(~conn)[0]
-            if not len(bad):
-                return t, r, metrics, costs
-            size = 1 << (len(bad) - 1).bit_length()
-            size = min(max(size, min(8, n)), n)
-            idx = bad[np.arange(size) % len(bad)]
-            t2, r2, batch2 = make(self._key(rng), idx)
-            c2, m2 = yield _tag(batch2, weights)
-            self.ev.n_generated += size
-            conn2 = np.asarray(m2["connected"]).astype(bool)
+        with span(spans.RESAMPLE):
+            costs = np.array(costs)
+            metrics = {k: np.array(v) for k, v in metrics.items()}
+            self.ev.n_generated += n
+            conn = metrics["connected"].astype(bool)
             if self.ev.archive is not None:
-                self.ev.archive.add(np.asarray(c2), t2, r2, valid=conn2)
-            slots, rows = [], []
-            for i in range(size):
-                s = int(idx[i])
-                if conn2[i] and not conn[s]:
-                    conn[s] = True
-                    slots.append(s)
-                    rows.append(i)
+                self.ev.archive.add(costs, t, r, valid=conn)
+            idx = _resample_slots(conn, n)
+        for _ in range(max_rounds):
+            if idx is None:
+                return t, r, metrics, costs
+            with span(spans.PRODUCE):
+                t2, r2, batch2 = make(self._key(rng), idx)
+            c2, m2 = yield _tag(batch2, weights)
+            with span(spans.RESAMPLE):
+                self.ev.n_generated += len(idx)
+                conn2 = np.asarray(m2["connected"]).astype(bool)
+                if self.ev.archive is not None:
+                    self.ev.archive.add(np.asarray(c2), t2, r2, valid=conn2)
+                slots, rows = [], []
+                for i in range(len(idx)):
+                    s = int(idx[i])
+                    if conn2[i] and not conn[s]:
+                        conn[s] = True
+                        slots.append(s)
+                        rows.append(i)
+                idx = _resample_slots(conn, n)
             if slots:
-                sl, rw = np.array(slots), np.array(rows)
-                t = t.at[jnp.asarray(sl)].set(t2[jnp.asarray(rw)])
-                r = r.at[jnp.asarray(sl)].set(r2[jnp.asarray(rw)])
-                for k, v in metrics.items():
-                    v[sl] = np.asarray(m2[k])[rw]
-                costs[sl] = np.asarray(c2)[rw]
+                with span(spans.REPAIR):
+                    sl, rw = np.array(slots), np.array(rows)
+                    t = t.at[jnp.asarray(sl)].set(t2[jnp.asarray(rw)])
+                    r = r.at[jnp.asarray(sl)].set(r2[jnp.asarray(rw)])
+                    for k, v in metrics.items():
+                        v[sl] = np.asarray(m2[k])[rw]
+                    costs[sl] = np.asarray(c2)[rw]
         raise RuntimeError(  # pragma: no cover - pathological architecture
             "could not batch-generate connected placements")
 
@@ -1020,6 +1030,18 @@ class DevicePipeline:
                                                     p_mutation))
 
 
+def _resample_slots(conn: np.ndarray, n: int) -> np.ndarray | None:
+    """Slots of the next resample round (``None`` once every slot is
+    connected): the unconnected ones, repeated up to a power of two of at
+    least min(8, n) and at most n."""
+    bad = np.nonzero(~conn)[0]
+    if not len(bad):
+        return None
+    size = 1 << (len(bad) - 1).bit_length()
+    size = min(max(size, min(8, n)), n)
+    return bad[np.arange(size) % len(bad)]
+
+
 def _sol_at(t, r, i: int):
     """Device batch row -> host Sol (matches the host operators' dtypes)."""
     return (np.asarray(t[i]), np.asarray(r[i]))
@@ -1045,17 +1067,18 @@ def best_random_batched_steps(ev: Evaluator, rng: np.random.Generator, *,
                                              t0, time_budget_s))
         t, r, metrics, costs = yield from pipe.sample_random_steps(
             rng, batch, weights=w)
-        res.n_evaluated += batch
-        i = int(np.argmin(costs))
-        if ev.schedule is not None:
-            pool_t.append(t[i])
-            pool_r.append(r[i])
-        if costs[i] < res.best_cost:
-            res.best_cost = float(costs[i])
-            res.best_sol = _sol_at(t, r, i)
-            res.best_metrics = _metrics_row(metrics, i)
-        res.history.append((time.monotonic() - t0, res.n_evaluated,
-                            res.best_cost))
+        with span(spans.SELECT, gen=len(res.history)):
+            res.n_evaluated += batch
+            i = int(np.argmin(costs))
+            if ev.schedule is not None:
+                pool_t.append(t[i])
+                pool_r.append(r[i])
+            if costs[i] < res.best_cost:
+                res.best_cost = float(costs[i])
+                res.best_sol = _sol_at(t, r, i)
+                res.best_metrics = _metrics_row(metrics, i)
+            res.history.append((time.monotonic() - t0, res.n_evaluated,
+                                res.best_cost))
     if ev.schedule is not None and pool_t:
         pt, pr = jnp.stack(pool_t), jnp.stack(pool_r)
         costs, metrics = yield _tag(pipe.rebuild(pt, pr),
@@ -1111,40 +1134,46 @@ def genetic_algorithm_batched_steps(ev: Evaluator,
             w_now = ev.sched_weights(_sched_progress(
                 gen, max_generations, t0, time_budget_s))
             costs, metrics = yield _tag(pipe.rebuild(t, r), w_now)
-        order = np.argsort(costs)
-        if costs[order[0]] < res.best_cost:
-            i = int(order[0])
-            res.best_cost = float(costs[i])
-            res.best_sol = _sol_at(t, r, i)
-            res.best_metrics = _metrics_row(metrics, i)
-        res.history.append((time.monotonic() - t0, res.n_evaluated,
-                            res.best_cost))
         gen += 1
-        if time_budget_s is not None and time.monotonic() - t0 > time_budget_s:
-            break
-        if max_generations is not None and gen >= max_generations:
-            break
+        # Selection runs in two spans, closed before the children are
+        # sampled: the sampler yields to the caller, who scores them.
+        with span(spans.SELECT, gen=gen):
+            order = np.argsort(costs)
+            if costs[order[0]] < res.best_cost:
+                i = int(order[0])
+                res.best_cost = float(costs[i])
+                res.best_sol = _sol_at(t, r, i)
+                res.best_metrics = _metrics_row(metrics, i)
+            res.history.append((time.monotonic() - t0, res.n_evaluated,
+                                res.best_cost))
+            if time_budget_s is not None and \
+                    time.monotonic() - t0 > time_budget_s:
+                break
+            if max_generations is not None and gen >= max_generations:
+                break
 
-        def tournament_pick() -> int:
-            idx = rng.choice(population, size=min(tournament, population),
-                             replace=False)
-            return int(idx[np.argmin(costs[idx])])
+            def tournament_pick() -> int:
+                idx = rng.choice(population, size=min(tournament, population),
+                                 replace=False)
+                return int(idx[np.argmin(costs[idx])])
 
-        n_child = population - elitism
-        pa = np.array([tournament_pick() for _ in range(n_child)])
-        pb = np.array([tournament_pick() for _ in range(n_child)])
-        w = ev.sched_weights(_sched_progress(gen, max_generations, t0,
-                                             time_budget_s))
+            n_child = population - elitism
+            pa = np.array([tournament_pick() for _ in range(n_child)])
+            pb = np.array([tournament_pick() for _ in range(n_child)])
+            w = ev.sched_weights(_sched_progress(gen, max_generations, t0,
+                                                 time_budget_s))
+            parents = (t[jnp.asarray(pa)], r[jnp.asarray(pa)],
+                       t[jnp.asarray(pb)], r[jnp.asarray(pb)])
         ct, cr, cm, ccosts = yield from pipe.sample_children_steps(
-            rng, t[jnp.asarray(pa)], r[jnp.asarray(pa)],
-            t[jnp.asarray(pb)], r[jnp.asarray(pb)], p_mutation, weights=w)
-        res.n_evaluated += n_child
-        elite = order[:elitism]
-        t = jnp.concatenate([t[jnp.asarray(elite)], ct])
-        r = jnp.concatenate([r[jnp.asarray(elite)], cr])
-        metrics = {k: np.concatenate([v[elite], cm[k]])
-                   for k, v in metrics.items()}
-        costs = np.concatenate([costs[elite], ccosts])
+            rng, *parents, p_mutation, weights=w)
+        with span(spans.SELECT, gen=gen):
+            res.n_evaluated += n_child
+            elite = order[:elitism]
+            t = jnp.concatenate([t[jnp.asarray(elite)], ct])
+            r = jnp.concatenate([r[jnp.asarray(elite)], cr])
+            metrics = {k: np.concatenate([v[elite], cm[k]])
+                       for k, v in metrics.items()}
+            costs = np.concatenate([costs[elite], ccosts])
     if ev.schedule is not None:
         costs, metrics = yield _tag(pipe.rebuild(t, r),
                                     ev.sched_weights(1.0))
@@ -1216,24 +1245,25 @@ def simulated_annealing_batched_steps(ev: Evaluator,
             # Incumbent costs are stale under ramped weights: re-score the
             # chain states so the Metropolis delta is exact at progress t.
             costs, _ = yield _tag(pipe.rebuild(t, r), w)
-        res.n_evaluated += chains
-        accept = _sa_accept(rng, ncosts - costs, temps)
-        acc = jnp.asarray(accept).reshape((-1,) + (1,) * (t.ndim - 1))
-        t = jnp.where(acc, nt, t)
-        r = jnp.where(acc, nr, r)
-        costs = np.where(accept, ncosts, costs)
-        block_costs.append(ncosts.copy())
-        i = int(np.argmin(ncosts))
-        if ncosts[i] < res.best_cost:
-            res.best_cost = float(ncosts[i])
-            res.best_sol = _sol_at(nt, nr, i)
-            res.best_metrics = _metrics_row(nm, i)
-        it += 1
-        if it % block_len == 0:
-            temps = _sa_cool(temps, block_costs, alpha, beta)
-            block_costs = []
-        res.history.append((time.monotonic() - tstart, res.n_evaluated,
-                            res.best_cost))
+        with span(spans.SELECT, gen=it):
+            res.n_evaluated += chains
+            accept = _sa_accept(rng, ncosts - costs, temps)
+            acc = jnp.asarray(accept).reshape((-1,) + (1,) * (t.ndim - 1))
+            t = jnp.where(acc, nt, t)
+            r = jnp.where(acc, nr, r)
+            costs = np.where(accept, ncosts, costs)
+            block_costs.append(ncosts.copy())
+            i = int(np.argmin(ncosts))
+            if ncosts[i] < res.best_cost:
+                res.best_cost = float(ncosts[i])
+                res.best_sol = _sol_at(nt, nr, i)
+                res.best_metrics = _metrics_row(nm, i)
+            it += 1
+            if it % block_len == 0:
+                temps = _sa_cool(temps, block_costs, alpha, beta)
+                block_costs = []
+            res.history.append((time.monotonic() - tstart, res.n_evaluated,
+                                res.best_cost))
     if ev.schedule is not None:
         fcosts, fmetrics = yield _tag(pipe.rebuild(t, r),
                                       ev.sched_weights(1.0))
