@@ -11,7 +11,10 @@ below keep the working set VMEM-resident instead:
   counts**: one grid program per placement, (V, V) D and N matrices live in
   VMEM for the entire V-step relaxation.  This is the kernel the scorer
   uses (exact same math as ``ref.fw_counts_ref``).  V is padded to a
-  multiple of 128 (lane width) with isolated nodes.
+  multiple of 128 (lane width) with isolated nodes, but the pivots and
+  the row strips stop at the true V: a pad pivot's candidates are at
+  least 1e9 + 1e9, above every real entry (at most 1e9), and never tie
+  below ``INF_CUT``, so it could change nothing that is returned.
 
 * ``minplus_tiled_pallas`` — blocked tropical matmul (distances only) for
   graphs too large for a VMEM-resident FW; the classic (i, j, k) tiling
@@ -130,26 +133,34 @@ def _strip_rows(V: int) -> int:
     return rs
 
 
-def _fw_counts_kernel(w_ref, d_ref, n_ref, *, V: int, rs: int):
-    """D and N live in the VMEM output blocks for all V pivots; each pivot
-    sweeps them in (rs, V) row strips.  Row k and column k are masked
-    from pivot k's update, so every strip reads their time-k values
-    whatever the sweep order."""
-    col = _iota((rs, V), 1)
+def _fw_counts_kernel(w_ref, d_ref, n_ref, *, V: int, Vp: int, rs: int):
+    """D and N live in the VMEM output blocks, [Vp, Vp] with V real
+    vertices.  Pivots run over k < V only, and each sweeps just the
+    ceil(V / rs) row strips of (rs, Vp) that hold real rows; lanes stay
+    Vp wide.  Row k and column k are masked from pivot k's update, so
+    every strip reads their time-k values whatever the sweep order.
 
-    def strips(fn):
+    Exact: a pivot k < V updates entry (i, j) from D[i, k] and D[k, j]
+    alone, so the real block goes through the reference's updates on the
+    unpadded graph.  The pivots left out are the isolated pad nodes,
+    whose candidates are at least 1e9 + 1e9 against real entries of at
+    most 1e9, and so never win and never tie below ``INF_CUT``; rows past
+    V are sliced off by the caller."""
+    col = _iota((rs, Vp), 1)
+
+    def strips(fn, n):
         def body(s, carry):
             r0 = pl.multiple_of(s * rs, rs)
-            fn(pl.ds(r0, rs), _iota((rs, V), 0, r0))
+            fn(pl.ds(r0, rs), _iota((rs, Vp), 0, r0))
             return carry
-        jax.lax.fori_loop(0, V // rs, body, 0)
+        jax.lax.fori_loop(0, n, body, 0)
 
     def init(rows, row):
         W = w_ref[0, rows, :]
         d_ref[0, rows, :] = W
         n_ref[0, rows, :] = _init_counts(W, row == col)
 
-    strips(init)
+    strips(init, Vp // rs)
 
     def pivot(k, carry):
         b_d, b_n = _row(d_ref, k), _row(n_ref, k)
@@ -161,7 +172,7 @@ def _fw_counts_kernel(w_ref, d_ref, n_ref, *, V: int, rs: int):
             d_ref[0, rows, :] = Td
             n_ref[0, rows, :] = Tn
 
-        strips(relax)
+        strips(relax, -(-V // rs))
         return carry
 
     jax.lax.fori_loop(0, V, pivot, 0)
@@ -189,10 +200,13 @@ def _pad_isolated(W: jnp.ndarray, Vp: int) -> jnp.ndarray:
 
 def fw_counts_pallas(W: jnp.ndarray, *, interpret: bool | None = None
                      ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Batched FW + counts.  W: [B, V, V] float32, V % 128 == 0 preferred.
+    """Batched FW + counts.  W: [B, V, V] (or [V, V]) float32.
 
-    Pads V up to a multiple of 128 with isolated nodes (diag 0, else INF);
-    padded rows/cols do not interact with real nodes.
+    Pads V up to a multiple of 128 with isolated nodes (diag 0, else INF)
+    for the lane axis, and passes the true V to the kernel, which runs
+    only the V real pivots over the row strips that hold real rows (see
+    ``_fw_counts_kernel`` for why that is exact).  At V % 128 == 0 the
+    kernel is the untrimmed program.
     """
     interpret = _resolve_interpret(interpret)
     squeeze = W.ndim == 2
@@ -201,7 +215,8 @@ def fw_counts_pallas(W: jnp.ndarray, *, interpret: bool | None = None
     B, V0, _ = W.shape
     Vp = max(128, -(-V0 // 128) * 128)
     W = _pad_isolated(W, Vp)
-    kern = functools.partial(_fw_counts_kernel, V=Vp, rs=_strip_rows(Vp))
+    kern = functools.partial(_fw_counts_kernel, V=V0, Vp=Vp,
+                             rs=_strip_rows(Vp))
     block = pl.BlockSpec((1, Vp, Vp), lambda b: (b, 0, 0))
     D, N = pl.pallas_call(
         kern,

@@ -36,14 +36,19 @@ def random_graph(V, n_edges, seed=0, batch=1):
     return W
 
 
+# V 120, 136 and 432 (homog64) are not multiples of the strip height or of
+# 128, so the kernel's trimmed pivot loop and row strips stop inside the
+# padding; the last two are sparse enough that some pairs stay at 1e9.
 @pytest.mark.parametrize("V,edges,batch", [(8, 12, 1), (40, 120, 2),
-                                           (130, 400, 1)])
+                                           (130, 400, 1), (120, 400, 1),
+                                           (136, 180, 2), (432, 560, 1)])
 def test_fw_counts_kernel(V, edges, batch):
     W = jnp.array(random_graph(V, edges, seed=V, batch=batch))
     D1, N1 = ops.fw_counts(W, impl="pallas")
     D2, N2 = ref.fw_counts_ref(W)
-    assert_allclose(np.array(D1), np.array(D2), rtol=0)
-    assert_allclose(np.array(N1), np.array(N2), rtol=0)
+    for got, want in ((D1, D2), (N1, N2)):
+        np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
+                                      np.asarray(want).view(np.uint32))
 
 
 @pytest.mark.parametrize("m,k,n,tiles", [(64, 64, 64, dict(bm=32, bn=32, bk=32)),

@@ -18,7 +18,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import ops
 from repro.kernels.minplus import fw_counts_pallas, fw_counts_tiled_pallas
 
-HOMOG64_VP = 512            # V = 432 (272 PHYs + 2 x 80 virtual nodes)
+HOMOG64_V = 432             # 272 PHYs + 2 x 80 virtual nodes
+HOMOG64_VP = 512            # its padded V
 MOSAIC = 'custom_call_target="tpu_custom_call"'
 
 
@@ -48,7 +49,10 @@ def _compile(fn, one_chip, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("V", [HOMOG64_VP, ops.FW_TILED_AUTO_V])
+# 432 and 700 pad to 512 and 768: the pivot loop and row strips stop
+# inside the padding.
+@pytest.mark.parametrize("V", [HOMOG64_V, HOMOG64_VP, 700,
+                               ops.FW_TILED_AUTO_V])
 def test_fw_counts_vmem_compiles(one_chip, V):
     txt = _compile(lambda W: fw_counts_pallas(W, interpret=False),
                    one_chip, (8, V, V))
