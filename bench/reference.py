@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import concurrent.futures
 import math
+import os
 
 import numpy as np
 
@@ -268,9 +269,18 @@ class Paths:
         self.Dst, self.Nst = _group_min(self.Dt, self.Nt, arch.groups, -2)
 
 
-def paths_of(graphs, dtype=np.float64, threads: int = 1):
-    """Paths of each graph; ``threads`` > 1 splits the graphs over a
-    thread pool (numpy releases the interpreter lock in its loops)."""
+def pool_size() -> int:
+    """Threads of the reference's pool: the CPUs this process may run on."""
+    try:
+        return len(os.sched_getaffinity(0))
+    except AttributeError:       # no affinity call on this platform
+        return os.cpu_count() or 1
+
+
+def paths_of(graphs, dtype=np.float64, pool=None, threads: int = 1):
+    """Paths of each graph.  With a ``pool`` of ``threads`` threads the
+    graphs are split into as many interleaved stacks, one a thread (numpy
+    releases the interpreter lock in its loops)."""
     arch = graphs[0].arch
 
     def run(gs):
@@ -279,11 +289,10 @@ def paths_of(graphs, dtype=np.float64, threads: int = 1):
         N = N.astype(np.float64)
         return [Paths(arch, D[i], N[i]) for i in range(len(gs))]
 
-    if threads <= 1 or len(graphs) == 1:
+    if pool is None or threads <= 1 or len(graphs) == 1:
         return run(graphs)
     parts = [graphs[i::threads] for i in range(threads) if graphs[i::threads]]
-    with concurrent.futures.ThreadPoolExecutor(len(parts)) as pool:
-        done = list(pool.map(run, parts))
+    done = list(pool.map(run, parts))
     out = [None] * len(graphs)
     for i, part in enumerate(done):
         out[i::len(parts)] = part
@@ -294,10 +303,13 @@ def paths_of(graphs, dtype=np.float64, threads: int = 1):
 # Metrics.
 # ---------------------------------------------------------------------------
 
-def _on_path_use(P: Paths, g: Graph, srcs, dsts):
+EDGE_BLOCK = 64      # links whose loads one step of ``_link_load`` sums
+
+
+def _on_path_use(P: Paths, g: Graph, srcs, dsts, edges):
     """[S, E, T] share of s -> t shortest-path traffic that crosses each
-    directed link (ECMP over all shortest paths)."""
-    eu, ev = g.edges[:, 0], g.edges[:, 1]
+    directed link of ``edges`` (ECMP over all shortest paths)."""
+    eu, ev = g.edges[edges, 0], g.edges[edges, 1]
     w = g.Wp[eu, ev]
     Dsd = P.Dst[np.ix_(srcs, dsts)]
     Dsu, Nsu = P.Ds[srcs][:, eu], P.Ns[srcs][:, eu]
@@ -307,6 +319,17 @@ def _on_path_use(P: Paths, g: Graph, srcs, dsts):
                   - Dsd[:, None, :]) < 0.5)
           & (Dsd[:, None, :] < INF_CUT))
     return np.where(on, Nsu[:, :, None] * Nvd[None] / Nsd[:, None, :], 0.0)
+
+
+def _link_load(P: Paths, g: Graph, srcs, dsts, dem):
+    """[E] load of each directed link under demand ``dem`` [S, T].  Each
+    link's load is its own sum over (s, t), so the links are taken
+    ``EDGE_BLOCK`` at a time: the [S, E, T] shares of a 256-chiplet arch
+    would hold 0.3 GB an array."""
+    return np.concatenate([
+        np.einsum("st,set->e", dem,
+                  _on_path_use(P, g, srcs, dsts, slice(a, a + EDGE_BLOCK)))
+        for a in range(0, len(g.edges), EDGE_BLOCK)] or [np.zeros(0)])
 
 
 def metrics(arch: Arch, g: Graph, P: Paths) -> dict:
@@ -326,14 +349,33 @@ def metrics(arch: Arch, g: Graph, P: Paths) -> dict:
         out[f"lat_{t}"] = float(np.where(ok, Dsd, 0.0).sum()
                                 / max(ok.sum(), 1))
         dem = ok / np.maximum(ok.sum(axis=1, keepdims=True), 1)
-        load = np.einsum("st,set->e", dem, _on_path_use(P, g, srcs, dsts))
+        load = _link_load(P, g, srcs, dsts, dem)
         top = load.max() if len(load) else 0.0
         out[f"thr_{t}"] = float(min(1.0, 1.0 / top)) if top > 0 else 1.0
     return out
 
 
+def metrics_of(arch: Arch, graphs, dtype=np.float64,
+               threads: int | None = None) -> list[dict]:
+    """``metrics`` of each graph, in order.  Floyd-Warshall and then the
+    metrics run on one pool of ``threads`` threads (``pool_size()`` by
+    default); each placement's numbers are computed alone, so they are
+    bit for bit what ``threads=1`` gives."""
+    if not graphs:
+        return []
+    threads = pool_size() if threads is None else threads
+    if threads <= 1:
+        return [metrics(arch, g, p)
+                for g, p in zip(graphs, paths_of(graphs, dtype))]
+    with concurrent.futures.ThreadPoolExecutor(threads) as pool:
+        paths = paths_of(graphs, dtype, pool, threads)
+        return list(pool.map(lambda gp: metrics(arch, *gp),
+                             zip(graphs, paths)))
+
+
 def normalizers(arch: Arch, draws, n: int, policy: str,
-                dtype=np.float64, threads: int = 8) -> dict | None:
+                dtype=np.float64, threads: int | None = None
+                ) -> dict | None:
     """§IV-B cost normalizers of a normalizer draw: ``draws`` are the
     placements drawn one after another, and the first ``n`` of them that
     are connected are scored and reduced by the objective's policy (the
@@ -352,8 +394,7 @@ def normalizers(arch: Arch, draws, n: int, policy: str,
                 break
     if len(kept) < n:
         return None
-    ms = [metrics(arch, g, p)
-          for g, p in zip(kept, paths_of(kept, dtype, threads))]
+    ms = metrics_of(arch, kept, dtype, threads)
     stat = {"mean": np.mean, "median": np.median}[policy]
     out = {}
     for t in TRAFFIC:

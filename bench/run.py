@@ -7,25 +7,45 @@
 A cell names a configuration (``bench/configs/<config>.json``: the arch,
 its chiplets and the search's hyper-parameters) and a traffic mix
 (``bench/traffic/<mix>.json``: the objective and the search's warm
-generations).  Set-up builds the placement representation and the
-Evaluator as ``run_sweep`` does, the host normalizer draw included, warms
-every shape the window uses, and turns on JAX's persistent compilation
-cache in ``.jax_cache/`` of the checkout.  Then one search starts: the
-registered optimizer's step generator under the paper's wall budget of
-3600 s, driven as ``run_sweep`` drives it.  Its first generations are
-set-up too; the window opens at the start of the next generation and
-closes at the first generation start after ``--seconds``, where the
-optimizer's own wall-budget check would stop it.
+generations).  A traffic file may also carry ``"search": {"optimizer":
+<name>, <params>}``: its keys override the configuration's
+``optimizer`` and parameter keys, and the parameters are built as the
+``params_cls`` the optimizer is registered with (``BRParams(batch=...)``,
+``GAParams(population=..., ...)``), from the keys that class has.
+
+Set-up builds the placement representation and the Evaluator as
+``run_sweep`` does, the host normalizer draw included, warms every shape
+the window uses, and turns on JAX's persistent compilation cache in
+``.jax_cache/`` of the checkout.  Then one search starts: the registered
+optimizer's step generator under the paper's wall budget of 3600 s,
+driven as ``run_sweep`` drives it.  Its first generations are set-up
+too; the window opens at the start of the next generation and closes at
+the first generation start after ``--seconds``, where the optimizer's
+own wall-budget check would stop it.  Only optimizers with a generation
+boundary the window can see run (``BOUNDARIES``): ``ga-batched``, whose
+generation starts as it samples its children, and ``br-batched``, whose
+generation is one batch of random placements.  Any other is refused
+with ``SetupError`` before set-up.
+
+A cell on one chip scores each request with ``optimize._score_request``
+on the default device.  A cell on more chips takes the route of a
+sharded ``run_sweep`` group: each request goes through
+``optimize.score_stacked`` with the population-sharded scorer
+(``sharding.population.shard_scorer``) over a mesh of the first
+``chips`` devices; warm-up builds that program at every resample size,
+and the result reports the fullest device's peak memory, with each
+device's beside it.
 
 With ``--trace 0`` the result's metrics are the cell's end-to-end
 metrics; with ``--trace 1`` the window runs under the profiler and the
 metrics are the cell's per-layer metrics, each read by
 ``bench/metrics/<name>.py``.  Either way the outputs of the window are
 checked against the float64 reference (``bench/reference.py``) once it
-has closed.  The last line of stdout is one JSON object; the numbers
-compared, each with its limit, are the last lines of stderr.  A run that
-finds no TPU, or fewer chips than the cell asks for, exits 2 and prints
-no result.
+has closed, on a thread pool as wide as the CPUs the process may use.
+The last line of stdout is one JSON object; the numbers compared, each
+with its limit, are the last lines of stderr.  A run that finds no TPU,
+fewer chips than the cell asks for, or an optimizer the window cannot
+bound, exits 2 and prints no result.
 """
 from __future__ import annotations
 
@@ -68,11 +88,38 @@ LIMITS = {
 SEARCH_BUDGET_S = 3600.0  # the paper's wall budget of one search
 SAMPLE_ROWS = 32        # placements of the window's last rounds checked,
 SAMPLE_CONNECTED = 24   # at most this many of them flagged connected
-KEEP_ROUNDS = 12        # scoring rounds the recorder keeps
+KEEP_ROUNDS = 12        # scoring rounds the recorder keeps,
+KEEP_BYTES = 2 << 30    # and no more of their weights W than this (device)
+
+# The pipeline sampler that one generation of each optimizer enters once,
+# at its start: the window's boundary.  ga-batched also draws its first
+# population through sample_random_steps, so that is not its boundary.
+BOUNDARIES = {"ga-batched": "sample_children_steps",
+              "br-batched": "sample_random_steps"}
 
 
 class SetupError(Exception):
     pass
+
+
+def search_of(config: dict, traffic: dict):
+    """The cell's optimizer and its typed parameters.  The traffic file's
+    ``search`` keys override the configuration's ``optimizer`` and
+    parameter keys; the parameters are the optimizer's registered
+    ``params_cls``, built from the keys it has (its defaults for the
+    rest).  An optimizer the window has no boundary for is refused."""
+    import dataclasses
+    from repro.core import api  # noqa: F401  (registers the optimizers)
+    from repro.core.registries import OPTIMIZERS
+
+    keys = {**config, **traffic.get("search", {})}
+    name = keys["optimizer"]
+    if name not in BOUNDARIES:
+        raise SetupError(f"optimizer {name!r} has no window boundary; the "
+                         f"benchmark runs {sorted(BOUNDARIES)}")
+    cls = OPTIMIZERS.get(name).params_cls
+    return name, cls(**{f.name: keys[f.name]
+                        for f in dataclasses.fields(cls) if f.name in keys})
 
 
 # ---------------------------------------------------------------------------
@@ -128,12 +175,14 @@ def device_kind_peaks(kind: str, root: str = ROOT) -> dict:
 class Recorder:
     """Keeps the last rounds of the search: each stage call's placements
     (and the stage's own connectivity flags, where it gives them) and the
-    scoring call that follows it.  Both wrappers open a profiler span, so
-    a trace shows the produce and score stages on the host."""
+    scoring call that follows it.  It keeps ``KEEP_ROUNDS`` rounds, fewer
+    where their weights W, which stay on the device, would pass
+    ``KEEP_BYTES``.  Both wrappers open a profiler span, so a trace shows
+    the produce and score stages on the host."""
 
     def __init__(self):
-        self.stages = collections.deque(maxlen=KEEP_ROUNDS)
-        self.scores = collections.deque(maxlen=KEEP_ROUNDS)
+        self.stages = collections.deque()
+        self.scores = collections.deque()
 
     def clear(self):
         self.stages.clear()
@@ -146,6 +195,8 @@ class Recorder:
             with jax.profiler.TraceAnnotation("bench.produce"):
                 t, r, batch = fn(*args)
             self.stages.append((t, r, batch.get("connected")))
+            while len(self.stages) > KEEP_ROUNDS:
+                self.stages.popleft()
             return t, r, batch
         return wrapped
 
@@ -156,6 +207,10 @@ class Recorder:
             with jax.profiler.TraceAnnotation("bench.score"):
                 out = fn(batch, *args, **kw)
             self.scores.append((batch["W"], out))
+            while len(self.scores) > KEEP_ROUNDS or (
+                    len(self.scores) > 1 and
+                    sum(W.nbytes for W, _ in self.scores) > KEEP_BYTES):
+                self.scores.popleft()
             return out
         return wrapped
 
@@ -171,13 +226,13 @@ class WindowClosed(Exception):
 
 
 class Window:
-    """The measured slice of one long search.  ``children(fn)`` wraps the
-    pipeline's per-generation child sampler: the search enters it at the
-    start of each generation.  The window opens as generation ``warm + 1``
-    starts and closes at the first generation start after ``seconds``,
-    where the optimizer's own wall-budget check would end the search: it
-    then raises ``WindowClosed`` out of the search.  With ``trace`` the
-    profiler runs for exactly the window."""
+    """The measured slice of one long search.  ``generations(fn)`` wraps
+    the pipeline sampler that the optimizer enters once at the start of
+    each generation (``BOUNDARIES``).  The window opens as generation
+    ``warm + 1`` starts and closes at the first generation start after
+    ``seconds``, where the optimizer's own wall-budget check would end the
+    search: it then raises ``WindowClosed`` out of the search.  With
+    ``trace`` the profiler runs for exactly the window."""
 
     def __init__(self, ev, rec: Recorder, warm: int, seconds: float,
                  trace: bool):
@@ -189,7 +244,7 @@ class Window:
         self.marks = []          # (time, kept) at each window generation
         self.span = None
 
-    def children(self, fn):
+    def generations(self, fn):
         def wrapped(*args, **kw):
             self._boundary()
             out = yield from fn(*args, **kw)
@@ -260,8 +315,9 @@ class CompileLog:
 def build(config: dict, traffic: dict):
     """Representation and Evaluator, with its host normalizer draw, built
     as ``run_sweep`` builds them for this config from the config's search
-    seed.  Every placement the draw takes is recorded, so that the
-    reference can work out the normalizers from the same data."""
+    seed, and the search's parameters (``search_of``).  Every placement
+    the draw takes is recorded, so that the reference can work out the
+    normalizers from the same data."""
     from repro.core import api
     from repro.core.chiplets import resolve_arch
     from repro.core.objective import Objective, TermSpec, TrafficMix
@@ -294,10 +350,7 @@ def build(config: dict, traffic: dict):
             backend=config["backend"], objective=objective)
     finally:
         del rep.random
-    params = api.GAParams(population=config["population"],
-                          elitism=config["elitism"],
-                          tournament=config["tournament"],
-                          p_mutation=config["p_mutation"])
+    _, params = search_of(config, traffic)
     return ref_arch, ev, params, draws
 
 
@@ -309,39 +362,47 @@ def resample_sizes(n: int) -> list[int]:
                          for k in range(1, n + 1)})
 
 
-def warm_up(ev, params, config: dict) -> None:
+def warm_up(ev, optimizer: str, params, config: dict, score) -> None:
     """Build every program the resample rounds run, on a random stream of
-    its own: the produce stages and the scorer at every resample batch
-    size, and the slot repairs at every count.  The search's own warm
-    generations build the rest before the window opens."""
+    its own: the produce stages and the scorer (``score``, the route the
+    search's requests take) at every resample batch size, and the slot
+    repairs at every count.  ga-batched: ``_gen`` at the population and
+    ``_child`` at the children; br-batched: ``_gen`` at the batch, and the
+    read of the best row.  The search's own warm generations build the
+    rest before the window opens."""
     import jax
     import jax.numpy as jnp
     from repro.core import optimize
 
     pipe = ev.pipeline()
     rng = np.random.default_rng([config["search_seed"], 1])
-    P = params.population
-    C = P - params.elitism
 
     def key():
         return jax.random.PRNGKey(int(rng.integers(2 ** 31 - 1)))
 
-    t0, r0, _ = pipe._gen(key(), P)
-    parents = [x[jnp.asarray(rng.integers(P, size=C))]
-               for x in (t0, r0, t0, r0)]
-    for n, make in (
-            (P, lambda s: pipe._gen(key(), s)),
-            (C, lambda s: pipe._child(
-                key(), *[x[jnp.asarray(np.arange(s) % C)]
-                         for x in parents], params.p_mutation))):
+    if optimizer == "ga-batched":
+        P = params.population
+        C = P - params.elitism
+        t0, r0, _ = pipe._gen(key(), P)
+        parents = [x[jnp.asarray(rng.integers(P, size=C))]
+                   for x in (t0, r0, t0, r0)]
+        stages = ((P, lambda s: pipe._gen(key(), s)),
+                  (C, lambda s: pipe._child(
+                      key(), *[x[jnp.asarray(np.arange(s) % C)]
+                               for x in parents], params.p_mutation)))
+    else:
+        stages = ((params.batch, lambda s: pipe._gen(key(), s)),)
+    for n, make in stages:
         full = make(n)
         for s in resample_sizes(n):
             t, r, batch = full if s == n else make(s)
-            optimize._score_request(ev, batch)
+            score(batch)
             for L in range(1, s + 1):
                 idx = jnp.asarray(np.arange(L))
                 for a, b in zip(full[:2], (t, r)):
                     a.at[idx].set(b[idx]).block_until_ready()
+        if optimizer == "br-batched":
+            optimize._sol_at(*full[:2], n - 1)
 
 
 # ---------------------------------------------------------------------------
@@ -387,16 +448,11 @@ def sample(rec: Recorder, seed: int) -> list[dict]:
     return items
 
 
-def reference_of(ref_arch, items, dtype=np.float64,
-                 threads: int = 8) -> list[dict]:
+def reference_of(ref_arch, items, dtype=np.float64) -> list[dict]:
     """The reference's graph, metrics and connectivity of each item."""
     graphs = [reference.graph_of(ref_arch, it["sol"]) for it in items]
-    if not graphs:
-        return []
-    paths = reference.paths_of(graphs, dtype=dtype, threads=threads)
     out = []
-    for g, p in zip(graphs, paths):
-        m = reference.metrics(ref_arch, g, p)
+    for g, m in zip(graphs, reference.metrics_of(ref_arch, graphs, dtype)):
         ok = m.pop("connected_paths")
         out.append({"graph": g, "metrics": m, "connected": ok})
     return out
@@ -446,14 +502,36 @@ def compare(items, refs, objective: dict, norms: dict, ref_norms):
 # One run.
 # ---------------------------------------------------------------------------
 
+def scorer_route(ev, chips: int):
+    """``score(request) -> (costs, metrics)`` for the search's requests.
+    One chip: ``optimize._score_request`` on the default device.  More:
+    the route ``run_sweep(shard=True)`` gives a stacked group, one
+    request stacked alone and scored by the population-sharded scorer
+    over the first ``chips`` devices, still through ``ev.score_batch``."""
+    from repro.core import optimize
+    if chips == 1:
+        return lambda req: optimize._score_request(ev, req)
+    import jax
+    from repro.sharding.population import population_mesh, shard_scorer
+    score_fn = shard_scorer(ev.scorer, population_mesh(jax.devices()[:chips]))
+
+    def score(req):
+        (out,), _ = optimize.score_stacked(
+            [(optimize._request_parts(req), ev)], score_fn=score_fn)
+        return out
+    return score
+
+
 def run_cell(spec: dict, seed: int, seconds: float, trace: bool, *,
              t_start: float = T_START) -> dict:
     """Set up, warm up, run the search through its warm generations and
     the window, and check what the window produced."""
     import jax
-    from repro.core import api, optimize
+    from repro.core import api
 
     config, traffic = spec["config"], spec["traffic"]
+    optimizer, _ = search_of(config, traffic)
+    chips = spec["cell"]["chips"]
     compiles = CompileLog()
     jax.monitoring.register_event_duration_secs_listener(compiles)
     try:
@@ -464,19 +542,21 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool, *,
         pipe = ev.pipeline()
         pipe._gen, pipe._child = rec.stage(pipe._gen), rec.stage(pipe._child)
         ev.score_batch = rec.score(ev.score_batch)
-        warm_up(ev, params, config)
+        score = scorer_route(ev, chips)
+        warm_up(ev, optimizer, params, config, score)
         t_search = time.perf_counter()
 
         win = Window(ev, rec, traffic["warm_generations"], seconds, trace)
-        pipe.sample_children_steps = win.children(pipe.sample_children_steps)
-        steps = api.stackable_steps(config["optimizer"])
+        boundary = BOUNDARIES[optimizer]
+        setattr(pipe, boundary, win.generations(getattr(pipe, boundary)))
+        steps = api.stackable_steps(optimizer)
         search = steps(ev, np.random.default_rng(api.algo_seed(
-            config["search_seed"], 0, config["optimizer"])),
+            config["search_seed"], 0, optimizer)),
             api.Budget(seconds=SEARCH_BUDGET_S), params)
         try:
             req = next(search)
             while True:
-                req = search.send(optimize._score_request(ev, req))
+                req = search.send(score(req))
         except WindowClosed:
             search.close()
         except StopIteration:
@@ -484,8 +564,9 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool, *,
     finally:
         jax.monitoring.unregister_event_duration_listener(compiles)
     t0, t1 = win.t0, win.t1
-    dev = jax.devices()[0]
-    stats = dev.memory_stats() or {}
+    devs = jax.devices()[:chips]
+    peaks = [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+             for d in devs]
     run = {"window_s": t1 - t0, "setup_s": t0 - t_start,
            "build_s": t_warm - t_build, "warmup_s": t_search - t_warm,
            "warm_search_s": t0 - t_search,
@@ -498,10 +579,11 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool, *,
            "draws": len(draws),
            "compiles_in_window": compiles.between(t0, t1),
            "trace": reduce.Reduction.from_dir(TRACE_DIR) if trace else None}
-    device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": len(jax.devices()),
-              "memory_peak_bytes": int(stats.get("peak_bytes_in_use", 0))}
+    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+              "count": chips, "memory_peak_bytes": max(peaks),
+              "memory_peak_bytes_each": peaks}
     norms = normalizers(ev)
+    t_check = time.perf_counter()
     items = sample(rec, seed)
     refs = reference_of(ref_arch, items)
     objective = traffic["objective"]
@@ -509,6 +591,7 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool, *,
                                       objective["normalizer"])
     numbers, failed, widest = compare(items, refs, objective, norms,
                                       ref_norms)
+    run["check_s"] = time.perf_counter() - t_check
     return {"run": run, "device": device, "numbers": numbers,
             "widest": widest, "checked": len(items), "failed": failed,
             "rec": rec, "norms": norms, "ref_norms": ref_norms,
@@ -582,6 +665,7 @@ def main(argv=None) -> int:
                              f"{len(devs)}")
         device_kind_peaks(devs[0].device_kind)
         enable_cache()
+        search_of(spec["config"], spec["traffic"])
     except SetupError as e:
         print(f"bench: {e}", file=sys.stderr)
         return 2
@@ -596,7 +680,8 @@ def main(argv=None) -> int:
           f"{run['draws']} placements {run['build_s']:.3f} s, warm-up "
           f"{run['warmup_s']:.3f} s, warm generations "
           f"{run['warm_search_s']:.3f} s, {run['setup_programs']} programs "
-          f"built); widest metric gap: {out['widest']}", file=sys.stderr)
+          f"built); check {run['check_s']:.3f} s; widest metric gap: "
+          f"{out['widest']}", file=sys.stderr)
     for k, v in line["checks"].items():
         print(f"check {k} {v['value']!r} limit {v['limit']!r}",
               file=sys.stderr)

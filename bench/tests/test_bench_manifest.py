@@ -60,6 +60,34 @@ def test_cells_resolve(manifest):
             assert w in {c["name"] for c in manifest["workloads"]}
 
 
+def _traffic_files():
+    """Every traffic file: the mixes of ``bench/traffic`` and the test
+    fixtures beside this file that hold an objective."""
+    import glob
+    for path in sorted(glob.glob(os.path.join(run.BENCH, "traffic", "*.json"))
+                       + glob.glob(os.path.join(run.BENCH, "tests",
+                                                "*.json"))):
+        with open(path) as f:
+            mix = json.load(f)
+        if "objective" in mix:
+            yield os.path.basename(path), mix
+
+
+def test_search_keys_name_registered_fields():
+    import dataclasses
+    from repro.core import api  # noqa: F401  (registers the optimizers)
+    from repro.core.registries import OPTIMIZERS
+    files = list(_traffic_files())
+    assert any("search" in mix for _, mix in files)
+    for name, mix in files:
+        if "search" not in mix:
+            continue
+        keys = dict(mix["search"])
+        entry = OPTIMIZERS.get(keys.pop("optimizer"))
+        fields = {f.name for f in dataclasses.fields(entry.params_cls)}
+        assert set(keys) <= fields, (name, set(keys) - fields)
+
+
 def test_unknown_cell_and_device_kind():
     with pytest.raises(run.SetupError):
         run.load_cell("no.such.cell")

@@ -143,3 +143,51 @@ def test_normalizers_agree_with_program(policy):
     for k, v in ref.items():
         assert R.rel_gap(prog[k], v) < 1e-5, k
     assert R.normalizers(arch, draws[:3], 6, policy) is None
+
+
+def test_pooled_reference_is_the_serial_reference():
+    """Floyd-Warshall and the metrics on a pool of threads give every
+    placement's metrics, and the normalizers reduced from them, bit for
+    bit as one thread does."""
+    from repro.core.api import make_rep
+    from repro.core.chiplets import resolve_arch
+    cfg = _config("homog32_small")
+    arch = R.Arch(cfg)
+    rep = make_rep(resolve_arch(cfg["arch"], "baseline"), cfg["arch"],
+                   cfg["mutation_mode"])
+    draws = _draws(rep, 2 ** 31 + 3, 200)
+    graphs = [R.graph_of(arch, s) for s in draws[:12]]
+    serial = R.metrics_of(arch, graphs, threads=1)
+    assert R.metrics_of(arch, graphs, threads=5) == serial
+    assert serial == [R.metrics(arch, g, p)
+                      for g, p in zip(graphs, R.paths_of(graphs))]
+    for policy in ("mean", "median"):
+        one = R.normalizers(arch, draws, 8, policy, threads=1)
+        assert one is not None
+        assert R.normalizers(arch, draws, 8, policy, threads=4) == one
+
+
+@pytest.mark.parametrize("block", [7, 64])
+def test_link_load_in_blocks_is_the_whole_sum(block, monkeypatch):
+    """Each link's load summed over its own (s, t) pairs, a block of
+    links at a time, is bit for bit the einsum over every link at once."""
+    from repro.core.api import make_rep
+    from repro.core.chiplets import resolve_arch
+    monkeypatch.setattr(R, "EDGE_BLOCK", block)
+    cfg = _config("homog32_small")
+    arch = R.Arch(cfg)
+    rep = make_rep(resolve_arch(cfg["arch"], "baseline"), cfg["arch"],
+                   cfg["mutation_mode"])
+    graphs = [R.graph_of(arch, s) for s in _draws(rep, 2 ** 31 + 9, 6)]
+    for g, P in zip(graphs, R.paths_of(graphs)):
+        assert len(g.edges) > block
+        for ks, kd in R.ENDPOINTS.values():
+            srcs = np.flatnonzero(arch.kinds == ks)
+            dsts = np.flatnonzero(arch.kinds == kd)
+            dem = np.random.default_rng(len(srcs)).random(
+                (len(srcs), len(dsts)))
+            whole = np.einsum("st,set->e", dem, R._on_path_use(
+                P, g, srcs, dsts, slice(None)))
+            got = R._link_load(P, g, srcs, dsts, dem)
+            assert got.view(np.uint64).tolist() == \
+                whole.view(np.uint64).tolist()
